@@ -21,9 +21,10 @@ Reports, for a small decoder LM on this host:
                           and the run fails if fused stops winning
   serve/decode_mesh_tp2   steady-state paged decode on a 2-device host
                           mesh (dp1xtp2: weights TP over 'model', page
-                          pools over 'data') — run in a subprocess with
-                          XLA_FLAGS=--xla_force_host_platform_device_count=2
-                          since the parent's jax is already initialized;
+                          pools over 'data') — on the CPU in a subprocess
+                          with XLA_FLAGS=--xla_force_host_platform_device_count=2
+                          since the parent's jax is already initialized,
+                          elsewhere in-process on the real devices;
                           the derived field carries the mesh label
   serve/ttft              time-to-first-token through the scheduler
   serve/e2e_sched         mixed-length queue end-to-end through the
@@ -58,6 +59,7 @@ from benchmarks.common import CSV, time_call
 from repro.configs.base import (MGRITConfig, ModelConfig, OptimizerConfig,
                                 RunConfig, ShapeConfig)
 from repro.launch.hostdev import force_host_device_count
+from repro.launch.mesh import make_mesh
 from repro.models import transformer
 from repro.serve.engine import Request, ServeEngine
 
@@ -95,9 +97,10 @@ def hybrid_rcfg() -> RunConfig:
 
 
 def mesh_probe(dp: int = 1, tp: int = 2) -> dict:
-    """Steady-state mesh-sharded paged decode throughput — called inside
-    a subprocess whose host platform was forced to ``dp * tp`` devices
-    (see :func:`_mesh_row`). Greedy output is conformance-checked against
+    """Steady-state mesh-sharded paged decode throughput on the first
+    ``dp * tp`` devices (on the CPU, a subprocess whose host platform was
+    forced to that count; see :func:`_mesh_row`). Greedy output is
+    conformance-checked against
     a single-device engine on the same weights before timing."""
     n = dp * tp
     if jax.device_count() < n:
@@ -106,8 +109,7 @@ def mesh_probe(dp: int = 1, tp: int = 2) -> dict:
         raise RuntimeError(
             f"mesh_probe needs {n} devices, have {jax.device_count()} "
             "(XLA_FLAGS already forced a smaller host device count?)")
-    mesh = jax.make_mesh((dp, tp), ("data", "model"),
-                         devices=jax.devices()[:n])
+    mesh = make_mesh((dp, tp), ("data", "model"))
     rcfg = serve_rcfg()
     params = transformer.init_model(jax.random.PRNGKey(0), rcfg)
     kw = dict(max_len=MAX_LEN, max_batch=BATCH, page_size=16)
@@ -128,9 +130,21 @@ def mesh_probe(dp: int = 1, tp: int = 2) -> dict:
 
 
 def _mesh_row(csv: CSV, dp: int = 1, tp: int = 2) -> None:
-    """serve/decode_mesh_tp2 in a subprocess: jax in THIS process is
-    already initialized with one CPU device, so the forced multi-device
-    host platform must come up in a fresh interpreter."""
+    """serve/decode_mesh_tp2. On an accelerator the probe runs in THIS
+    process on its real devices (raising when there are too few): this
+    process holds the chip, so a child could not reach it. On the CPU,
+    jax here is already initialized with one device, so the forced
+    multi-device host platform comes up in a fresh interpreter."""
+    if jax.default_backend() != "cpu":
+        out = mesh_probe(dp, tp)
+    else:
+        out = _mesh_probe_cpu_child(dp, tp)
+    csv.add(f"serve/decode_mesh_tp{tp}", BATCH / out["tok_s"] * 1e6,
+            f"tok_s={out['tok_s']:.0f};mesh={out['mesh']};"
+            f"devices={out['devices']}")
+
+
+def _mesh_probe_cpu_child(dp: int, tp: int) -> dict:
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     # appends to (not replaces) any operator-set XLA_FLAGS so this row
     # is timed under the same XLA settings as the sibling serve rows
@@ -145,10 +159,7 @@ def _mesh_row(csv: CSV, dp: int = 1, tp: int = 2) -> None:
                            f"{r.stderr[-2000:]}")
     line = [ln for ln in r.stdout.splitlines()
             if ln.startswith("RESULT ")][-1]
-    out = json.loads(line[len("RESULT "):])
-    csv.add(f"serve/decode_mesh_tp{tp}", BATCH / out["tok_s"] * 1e6,
-            f"tok_s={out['tok_s']:.0f};mesh={out['mesh']};"
-            f"devices={out['devices']}")
+    return json.loads(line[len("RESULT "):])
 
 
 def run(csv: CSV):

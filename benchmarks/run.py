@@ -14,6 +14,8 @@
 
 Prints ``name,us_per_call,derived`` CSV; ``--emit-json PATH`` also writes
 the rows as JSON for the CI regression gate (benchmarks.check_regression).
+A benchmark that raises still gets its ``_meta/<name>`` ERROR row and the
+others still run, but the process then exits 1.
 Usage: PYTHONPATH=src python -m benchmarks.run [--only NAME] [--fast]
            [--emit-json BENCH_ci.json]
 """
@@ -28,6 +30,7 @@ import traceback
 sys.path.insert(0, "src")
 
 from benchmarks.common import CSV  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 ALL = ("kernels", "roofline", "perf_report", "scaling", "dp_lp", "serve",
        "traffic", "spec", "convergence", "indicator", "buffer",
@@ -37,7 +40,7 @@ FAST = ("kernels", "roofline", "perf_report", "scaling", "dp_lp", "serve",
         "traffic", "spec")
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
     ap.add_argument("--fast", action="store_true",
@@ -45,11 +48,13 @@ def main(argv=None) -> None:
     ap.add_argument("--emit-json", default="",
                     help="also write results to this JSON file")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     names = [n for n in ALL if not args.only or n in args.only.split(",")]
     if args.fast:
         names = [n for n in names if n in FAST]
     csv = CSV()
+    failed = []
     for name in names:
         mod = __import__(f"benchmarks.bench_{name}", fromlist=["run"])
         t0 = time.time()
@@ -58,6 +63,7 @@ def main(argv=None) -> None:
             csv.add(f"_meta/{name}", (time.time() - t0) * 1e6, "ok")
         except Exception as e:
             traceback.print_exc()
+            failed.append(name)
             csv.add(f"_meta/{name}", (time.time() - t0) * 1e6,
                     f"ERROR={type(e).__name__}")
     print("name,us_per_call,derived")
@@ -67,7 +73,11 @@ def main(argv=None) -> None:
                    for n, us, derived in csv.rows}
         with open(args.emit_json, "w") as f:
             json.dump(payload, f, indent=1, sort_keys=True)
+    if failed:
+        print(f"benchmarks failed: {','.join(failed)}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

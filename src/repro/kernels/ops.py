@@ -17,7 +17,9 @@ Two tiers of dispatch:
   "ref" (a jnp implementation of identical math, the CPU default — XLA
   serves it fast, and the kernel files document that ref and kernel are
   oracle-checked against each other in ``tests/test_kernels_paged.py``).
-  ``REPRO_KERNEL_MODE`` overrides the default for debugging.
+  ``REPRO_KERNEL_MODE`` overrides the default for debugging on the CPU.
+  On a TPU only "pallas" is accepted: a run there that silently served
+  through the jnp arm would not be measuring the kernels.
 """
 from __future__ import annotations
 
@@ -44,13 +46,17 @@ def kernel_mode(mode: str | None = None) -> str:
     """Resolve the paged-kernel execution mode (single owner).
 
     Explicit argument wins, then the ``REPRO_KERNEL_MODE`` env var, then
-    the platform default: compiled Pallas on TPU, jnp ref on CPU.
+    the platform default: compiled Pallas on TPU, jnp ref on CPU. Asking
+    for "ref" or "interpret" on a TPU raises.
     """
     if mode is None:
         mode = os.environ.get("REPRO_KERNEL_MODE") or (
             "ref" if _interpret_default() else "pallas")
     if mode not in _MODES:
         raise ValueError(f"kernel mode {mode!r} not in {_MODES}")
+    if mode != "pallas" and jax.default_backend() == "tpu":
+        raise ValueError(f"kernel mode {mode!r} on a TPU: the paged "
+                         "kernels run compiled there (mode 'pallas')")
     return mode
 
 
@@ -101,10 +107,8 @@ def paged_attention(q, pk, pv, page_table, lengths, *, mode=None):
     mode = kernel_mode(mode)
     if mode == "ref":
         return pa.paged_attention_ref(q, pk, pv, page_table, lengths)
-    out = pa.paged_flash_attention_bhsd(
-        q.transpose(0, 2, 1, 3), pk, pv, page_table, lengths,
-        interpret=(mode == "interpret"))
-    return out.transpose(0, 2, 1, 3)
+    return pa.paged_flash_attention(q, pk, pv, page_table, lengths,
+                                    interpret=(mode == "interpret"))
 
 
 @functools.partial(jax.jit, static_argnames=("order", "mode"))

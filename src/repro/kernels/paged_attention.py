@@ -6,11 +6,14 @@ pool (n_pages, page_size, Hkv, hd) and each batch slot owns a row of the
 page table. The kernel walks that row **in-kernel** — the page table and
 per-slot lengths are scalar-prefetch operands, so the BlockSpec index maps
 resolve logical page p of slot b to physical page ``page_table[b, p]``
-while the grid runs. One grid block per (slot, head, page); the online-
-softmax state (m, l, acc) for the S query rows lives in VMEM scratch
-across the sequential page dimension, exactly like the prefill kernel's
-k-block dimension. GQA stays an index-map concern: query head h reads KV
-head ``h // group``.
+while the grid runs. One grid block per (slot, query-row tile, page): the
+page's K and V for all kv heads arrive as one (page_size*Hkv, hd) block,
+every (token, head) query row of the tile scores it in one dot, and a
+mask keeps query head h on kv head ``h // group`` (GQA). The online-
+softmax state (m, l, acc) lives in VMEM scratch across the sequential
+page dimension, exactly like the prefill kernel's k-block dimension.
+Blocks span whole trailing dimensions, as Mosaic's (8, 128) tiling
+requires of a kv-head axis of 8 or fewer.
 
 Dead pages (beyond ``lengths[b] + S - 1``) are skipped with ``pl.when`` —
 the page walk does the work the gathered-dense-view path spends on a
@@ -35,12 +38,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+_ROW_TILE = 512          # query rows (token x head) per grid step, at most
 
 
 def _paged_flash_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                         m_ref, l_ref, acc_ref, *, page_size: int,
-                        n_pages: int, s_q: int, scale: float):
+                        n_pages: int, s_q: int, n_heads: int, n_kv: int,
+                        scale: float):
     b = pl.program_id(0)
+    tile = pl.program_id(1)       # query-row tile
     p = pl.program_id(2)          # logical page index (sequential)
 
     @pl.when(p == 0)
@@ -55,79 +61,99 @@ def _paged_flash_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _page():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # (s_q, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (ps, hd)
+        q = q_ref[0].astype(jnp.float32) * scale       # (tq, hd)
+        k = k_ref[0].astype(jnp.float32)               # (ps*Hkv, hd)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        qpos = len_ref[b] + jax.lax.broadcasted_iota(
-            jnp.int32, (s_q, page_size), 0)
-        kpos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (s_q, page_size), 1)
-        # one mask covers causality AND staleness: key slots past a
-        # query's absolute position are either future tokens or garbage
-        # beyond the slot's written length
-        s = jnp.where(kpos <= qpos, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        pexp = jnp.exp(s - m_new[:, None])
+        shape = s.shape                                # (tq, ps*Hkv)
+        row = tile * shape[0] + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        # query row r = (token r // H, head r % H); key column c =
+        # (page slot c // Hkv, kv head c % Hkv). A row scores only its
+        # own GQA kv head, and one mask covers causality AND staleness:
+        # key slots past a query's absolute position are either future
+        # tokens or garbage beyond the slot's written length
+        qpos = len_ref[b] + row // n_heads
+        kpos = p * page_size + col // n_kv
+        own = (row % n_heads) // (n_heads // n_kv) == col % n_kv
+        s = jnp.where(own & (kpos <= qpos), s, NEG_INF)
+        m_prev = m_ref[...]                            # (tq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        pexp = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(pexp, axis=1)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(pexp, axis=1,
+                                                 keepdims=True)
         m_ref[...] = m_new
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+        v = v_ref[0].astype(jnp.float32)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             pexp, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(p == n_pages - 1)
     def _finish():
-        o_ref[0, 0, ...] = (acc_ref[...]
-                            / jnp.maximum(l_ref[...][:, None], 1e-30)
-                            ).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
-def paged_flash_attention_bhsd(q, pk, pv, page_table, lengths, *,
-                               interpret: bool = False):
-    """q: (B, H, S, hd); pk/pv: (n_pages, page_size, Hkv, hd);
-    page_table: (B, P) int32; lengths: (B,) int32 — q row i of slot b sits
-    at absolute position ``lengths[b] + i``. Returns (B, H, S, hd).
+def paged_flash_attention(q, pk, pv, page_table, lengths, *,
+                          interpret: bool = False):
+    """q: (B, S, H, hd) model layout; pk/pv: (n_pages, page_size, Hkv,
+    hd); page_table: (B, P) int32; lengths: (B,) int32 — q row i of slot
+    b sits at absolute position ``lengths[b] + i``. Returns (B, S, H,
+    hd).
 
-    S is tiny (1 in steady-state decode, k+1 in speculative verify, the
-    prompt bucket in chunked prefill); the page walk supplies the K
-    extent, so P — not S — carries the flash tiling.
+    One grid step per (slot, query-row tile, page): the page's keys for
+    every kv head load once, as a (page_size*Hkv, hd) block, and every
+    query head of the tile scores them in one dot, with a mask keeping
+    each head on its own kv head. Query rows are (token, head) pairs,
+    tiled at ``_ROW_TILE`` so that a long prefill chunk stays inside the
+    kernel's scoped VMEM. Both reshapes below are free (row-major merges of
+    adjacent axes), and every block spans whole trailing dimensions,
+    which is what Mosaic's (8, 128) tiling asks of a block whose
+    dimensions are not multiples of the tile. S is tiny (1 in
+    steady-state decode, k+1 in speculative verify, the prompt bucket in
+    chunked prefill); the page walk supplies the K extent.
     """
-    B, H, S, hd = q.shape
-    Hkv, page_size = pk.shape[2], pk.shape[1]
+    B, S, H, hd = q.shape
+    n_pages, page_size, Hkv = pk.shape[:3]
     P = page_table.shape[1]
-    g = H // Hkv
+    rows, cols = S * H, page_size * Hkv
+    tq = rows
+    while tq > _ROW_TILE and tq % 16 == 0:
+        tq //= 2
     scale = 1.0 / math.sqrt(hd)
 
     kernel = functools.partial(_paged_flash_kernel, page_size=page_size,
-                               n_pages=P, s_q=S, scale=scale)
+                               n_pages=P, s_q=S, n_heads=H, n_kv=Hkv,
+                               scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H, P),
+        grid=(B, rows // tq, P),
         in_specs=[
-            pl.BlockSpec((1, 1, S, hd),
-                         lambda b, h, p, pt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda b, h, p, pt, ln: (pt[b, p], 0, h // g, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda b, h, p, pt, ln: (pt[b, p], 0, h // g, 0)),
+            pl.BlockSpec((1, tq, hd), lambda b, i, p, pt, ln: (b, i, 0)),
+            pl.BlockSpec((1, cols, hd),
+                         lambda b, i, p, pt, ln: (pt[b, p], 0, 0)),
+            pl.BlockSpec((1, cols, hd),
+                         lambda b, i, p, pt, ln: (pt[b, p], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, S, hd),
-                               lambda b, h, p, pt, ln: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, tq, hd),
+                               lambda b, i, p, pt, ln: (b, i, 0)),
         scratch_shapes=[
-            pltpu.VMEM((S,), jnp.float32),        # m
-            pltpu.VMEM((S,), jnp.float32),        # l
-            pltpu.VMEM((S, hd), jnp.float32),     # acc
+            pltpu.VMEM((tq, 1), jnp.float32),         # m
+            pltpu.VMEM((tq, 1), jnp.float32),         # l
+            pltpu.VMEM((tq, hd), jnp.float32),        # acc
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, S, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, rows, hd), q.dtype),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), q, pk, pv)
+    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
+      q.reshape(B, rows, hd), pk.reshape(n_pages, cols, hd),
+      pv.reshape(n_pages, cols, hd))
+    return out.reshape(B, S, H, hd)
 
 
 def _dot_attention_paged(q, kd, vd, lengths, *, scale=None):

@@ -39,6 +39,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_MAX_ROW_TILE = 512
+
 
 def max_write_pages(seq_len: int, page_size: int) -> int:
     """Most snapshot pages S consecutive tokens can finalize, over every
@@ -46,11 +48,23 @@ def max_write_pages(seq_len: int, page_size: int) -> int:
     return (seq_len + page_size - 2) // page_size + 1
 
 
+def _row_tile(R: int) -> int:
+    """Rows per grid step: rows are independent recurrences, and a
+    (rows, ds) f32 block pads ds up to 128 lanes in VMEM, so R = 8192
+    whole would take 4 MiB per block. Tiles stay multiples of 128 (the
+    lane width of the (B, S, R) dt/x/y blocks); an R that 128 does not
+    divide runs whole."""
+    tr = R
+    while tr > _MAX_ROW_TILE and tr % 256 == 0:
+        tr //= 2
+    return tr
+
+
 def _paged_ssm_kernel(rp_ref, pw_ref, tw_ref, nn_ref, lv_ref,
                       dt_ref, x_ref, b_ref, c_ref, a_ref, hin_ref,
                       y_ref, hout_ref, h_scr, *, order: str):
     b = pl.program_id(0)
-    w = pl.program_id(1)
+    w = pl.program_id(2)
 
     @pl.when(w == 0)
     def _init():
@@ -102,25 +116,27 @@ def paged_ssm_update_pallas(dt, x, Bm, Cm, A, h_pool, read_page, live,
     ds = Bm.shape[-1]
     N = h_pool.shape[0]
     W = phys_w.shape[1]
+    tr = _row_tile(R)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=(B, W),
+        grid=(B, R // tr, W),
         in_specs=[
-            pl.BlockSpec((1, S, R), lambda b, w, *_: (b, 0, 0)),     # dt
-            pl.BlockSpec((1, S, R), lambda b, w, *_: (b, 0, 0)),     # x
-            pl.BlockSpec((1, S, ds), lambda b, w, *_: (b, 0, 0)),    # Bm
-            pl.BlockSpec((1, S, ds), lambda b, w, *_: (b, 0, 0)),    # Cm
-            pl.BlockSpec((R, ds), lambda b, w, *_: (0, 0)),          # A
-            pl.BlockSpec((1, R, ds),
-                         lambda b, w, rp, pw, tw, nn, lv: (rp[b], 0, 0)),
+            pl.BlockSpec((1, S, tr), lambda b, r, w, *_: (b, 0, r)),  # dt
+            pl.BlockSpec((1, S, tr), lambda b, r, w, *_: (b, 0, r)),  # x
+            pl.BlockSpec((1, S, ds), lambda b, r, w, *_: (b, 0, 0)),  # Bm
+            pl.BlockSpec((1, S, ds), lambda b, r, w, *_: (b, 0, 0)),  # Cm
+            pl.BlockSpec((tr, ds), lambda b, r, w, *_: (r, 0)),       # A
+            pl.BlockSpec((1, tr, ds),
+                         lambda b, r, w, rp, pw, tw, nn, lv: (rp[b], r, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, S, R), lambda b, w, *_: (b, 0, 0)),     # y
-            pl.BlockSpec((1, R, ds),
-                         lambda b, w, rp, pw, tw, nn, lv: (pw[b, w], 0, 0)),
+            pl.BlockSpec((1, S, tr), lambda b, r, w, *_: (b, 0, r)),  # y
+            pl.BlockSpec((1, tr, ds),
+                         lambda b, r, w, rp, pw, tw, nn, lv:
+                         (pw[b, w], r, 0)),
         ],
-        scratch_shapes=[pltpu.VMEM((R, ds), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((tr, ds), jnp.float32)],
     )
     y, new_pool = pl.pallas_call(
         functools.partial(_paged_ssm_kernel, order=order),

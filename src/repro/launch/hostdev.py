@@ -20,8 +20,8 @@ def force_host_device_count(
     (default ``os.environ``), preserving any operator-set flags. A
     pre-existing ``--xla_force_host_platform_device_count`` wins — the
     caller must then cope with whatever device count comes up (e.g.
-    ``jax.make_mesh(..., devices=jax.devices()[:n])`` + an explicit
-    count check)."""
+    ``launch.mesh.make_mesh``, which takes the first ``prod(shape)``
+    devices, + an explicit count check)."""
     env = os.environ if env is None else env
     flags = env.get("XLA_FLAGS", "")
     if "--xla_force_host_platform_device_count" in flags:

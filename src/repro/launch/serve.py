@@ -127,10 +127,13 @@ def main(argv=None):
     import jax
     from repro.configs import registry
     from repro.configs.reduce import reduce_config
+    from repro.launch.mesh import make_mesh
     from repro.models import transformer
     from repro.serve.engine import Request, ServeEngine
     from repro.serve.spec import SpecConfig
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     mesh = None
     if mesh_shape is not None:
         n = mesh_shape[0] * mesh_shape[1]
@@ -138,8 +141,7 @@ def main(argv=None):
             raise SystemExit(
                 f"--mesh {args.mesh} needs {n} devices, have "
                 f"{jax.device_count()} (XLA_FLAGS was set too late?)")
-        mesh = jax.make_mesh(mesh_shape, ("data", "model"),
-                             devices=jax.devices()[:n])
+        mesh = make_mesh(mesh_shape, ("data", "model"))
 
     rcfg = registry.get_config(args.arch, "decode_32k")
     if args.reduced:

@@ -40,9 +40,11 @@ def main(argv=None):
 
     from repro.configs import registry
     from repro.configs.reduce import reduce_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_production_mesh
     from repro.train.trainer import Trainer
 
+    enable_compile_cache()
     rcfg = registry.get_config(args.arch, args.shape)
     if args.reduced:
         rcfg = reduce_config(rcfg)

@@ -9,7 +9,10 @@
   * straggler watch: EWMA of step wall-time, slow steps logged.
 
 The LP and serial steps are two separately jitted functions; switching is a
-host-side decision (it happens once per run, like the paper's).
+host-side decision (it happens once per run, like the paper's). Under a
+mesh, params and optimizer state are placed by ``parallel/params.
+param_specs`` (the paper's layout puts the stacked trunk's layer axis on
+'model') and both steps keep that placement from call to call.
 """
 from __future__ import annotations
 
@@ -55,6 +58,7 @@ class Trainer:
             rcfg.optimizer, self.params,
             moment_dtype=jnp.dtype(rcfg.optimizer.moment_dtype))
         self.step = 0
+        self._shardings = None
         self._steps: Dict[str, Callable] = {}
         self._probe_fn = None
         self._ewma_dt = None
@@ -66,6 +70,13 @@ class Trainer:
                 self.params, self.opt_state, self.step, extra = restored
                 if extra.get("controller_mode"):
                     self.controller.state.mode = extra["controller_mode"]
+        if mesh is not None:
+            self._shardings = steps_mod.shardings_for_train(
+                rcfg, mesh, self.params, self.opt_state,
+                self.pipeline.batch_at(0))
+            ps, os_, _ = self._shardings
+            self.params = jax.device_put(self.params, ps)
+            self.opt_state = jax.device_put(self.opt_state, os_)
 
     # -- jitted steps (built lazily, cached per mode) --
     def _step_fn(self, mode: str):
@@ -74,8 +85,14 @@ class Trainer:
             if mode == "serial":
                 rcfg = rcfg.replace(
                     mgrit=dataclasses.replace(rcfg.mgrit, enabled=False))
-            self._steps[mode] = jax.jit(steps_mod.make_train_fn(
-                rcfg, self.mesh), donate_argnums=(0, 1))
+            fn = steps_mod.make_train_fn(rcfg, self.mesh)
+            if self._shardings is None:
+                self._steps[mode] = jax.jit(fn, donate_argnums=(0, 1))
+            else:
+                ps, os_, bs = self._shardings
+                self._steps[mode] = jax.jit(
+                    fn, donate_argnums=(0, 1), in_shardings=(ps, os_, bs),
+                    out_shardings=(ps, os_, None))
         return self._steps[mode]
 
     def _probe(self, batch):

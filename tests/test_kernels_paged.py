@@ -62,6 +62,18 @@ def test_paged_attention_interpret_matches_ref(B, S, H, Hkv, hd):
                                rtol=2e-5, atol=2e-5)
 
 
+def test_paged_attention_row_tiles_match_ref():
+    """A prefill chunk of S*H = 1024 query rows runs as two row tiles of
+    512, each with its own online-softmax state, over 8 kv heads."""
+    q, pk, pv, table, lengths = _attn_case(
+        jax.random.PRNGKey(10), 2, 64, 16, 8, 16, page_size=4,
+        pages_per_slot=20)
+    ref = kops.paged_attention(q, pk, pv, table, lengths, mode="ref")
+    out = kops.paged_attention(q, pk, pv, table, lengths, mode="interpret")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
 def test_paged_attention_ignores_garbage_beyond_length():
     """Pool rows past each slot's causal frontier carry exactly-zero
     probability mass: poisoning them with huge values must not move a
@@ -147,6 +159,26 @@ def test_paged_ssm_update_interpret_matches_ref(order, S, lengths, n_new):
                                rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("order", ["dbx", "dxb"])
+def test_paged_ssm_update_row_tiles_match_ref(order):
+    """R = 1024 rows run as two tiles of 512 (rows are independent
+    recurrences); a slot crossing a page boundary mid-chunk."""
+    S, lengths, n_new = 4, [3, 5], [4, 2]
+    args = _ssm_case(jax.random.PRNGKey(11), 2, S, R=1024, ds=4,
+                     page_size=4, pages_per_slot=3, lengths=lengths,
+                     n_new=n_new)
+    y_ref, pool_ref = kops.paged_ssm_update(*args, order=order, mode="ref")
+    y_int, pool_int = kops.paged_ssm_update(*args, order=order,
+                                            mode="interpret")
+    valid = (np.arange(S)[None, :] < np.asarray(n_new)[:, None])[..., None]
+    np.testing.assert_allclose(np.asarray(y_int) * valid,
+                               np.asarray(y_ref) * valid,
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(pool_int)[1:],
+                               np.asarray(pool_ref)[1:],
+                               rtol=1e-5, atol=1e-6)
+
+
 def test_paged_ssm_update_touches_only_planned_pages():
     """The compact write plan is what it claims: pages outside phys_w
     (and scratch) come back bit-identical — idle slots' state survives."""
@@ -185,8 +217,9 @@ def test_topk_topp_mask_matches_sort_based_masking():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_topk_topp_mask_interpret_matches_ref():
-    logits, top_ks, top_ps = _sampling_case(jax.random.PRNGKey(9))
+@pytest.mark.parametrize("V", [128, 200])     # 200: padded to 256 lanes
+def test_topk_topp_mask_interpret_matches_ref(V):
+    logits, top_ks, top_ps = _sampling_case(jax.random.PRNGKey(9), V=V)
     ref = kops.topk_topp_mask(logits, top_ks, top_ps, mode="ref")
     out = kops.topk_topp_mask(logits, top_ks, top_ps, mode="interpret")
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))

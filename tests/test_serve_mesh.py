@@ -11,7 +11,7 @@ requests must reproduce the oracle stream too (the sampler keys off
 run asserts greedy spec == plain paged decode under tp.
 
 The engines under test are real ``ServeEngine``s built with
-``mesh=jax.make_mesh((dp, tp), ("data", "model"))`` — the same
+``mesh=make_mesh((dp, tp), ("data", "model"))`` (``repro.launch.mesh``) — the same
 scheduler/allocator/trie paths as single-device serving; only the jitted
 calls go SPMD (serve/cache.CacheBackend, docs/sharding.md).
 """
@@ -36,6 +36,7 @@ _MESH_SCRIPT = textwrap.dedent("""
     import json
     import jax
     import numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.models import transformer
     from repro.serve.engine import Request, ServeEngine
     from repro.serve.spec import SpecConfig
@@ -61,7 +62,7 @@ _MESH_SCRIPT = textwrap.dedent("""
         refs = [dense_decode_oracle(rcfg, params, step, r, MAX_LEN)
                 for r in reqs()]
         for dp, tp in ((2, 1), (1, 2)):
-            mesh = jax.make_mesh((dp, tp), ("data", "model"))
+            mesh = make_mesh((dp, tp), ("data", "model"))
             eng = ServeEngine(rcfg, params, mesh=mesh, max_len=MAX_LEN,
                               max_batch=2, page_size=4)
             got = eng.generate(reqs())
@@ -89,7 +90,7 @@ _MESH_SCRIPT = textwrap.dedent("""
     out["fused_flag"] = []
     out["fused_mesh_mismatch"] = []
     for dp, tp in ((2, 1), (1, 2)):
-        mesh = jax.make_mesh((dp, tp), ("data", "model"))
+        mesh = make_mesh((dp, tp), ("data", "model"))
         for name in ("decoder", "ssm_mamba1"):
             rcfg = family_rcfg(name)
             params = transformer.init_model(
@@ -112,7 +113,7 @@ _MESH_SCRIPT = textwrap.dedent("""
     # (ssm_paged_commit_step) inside the SPMD verify call, hybrid the
     # composite in-line-KV + deferred-snapshot commit path
     out["spec_drafted"] = 0
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     for name in ("decoder", "ssm_mamba1", "hybrid"):
         rcfg = family_rcfg(name)
         params = transformer.init_model(

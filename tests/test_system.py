@@ -85,9 +85,10 @@ _SHARDING_SCRIPT = textwrap.dedent("""
     import numpy as np
     from repro.configs import registry
     from repro.launch import specs as specs_mod
+    from repro.launch.mesh import make_mesh
     from repro.parallel.params import param_specs, batch_specs
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     out = {}
     for arch in ("deepseek_7b", "qwen3_moe_235b", "falcon_mamba_7b"):
         rcfg = registry.get_config(arch, "train_4k")
@@ -117,6 +118,79 @@ def test_sharding_rules_subprocess():
     # layers->model axis
     for arch, n in out.items():
         assert n >= 5, f"{arch}: only {n} layer-sharded leaves"
+
+
+_MESH_TRAIN_SCRIPT = textwrap.dedent("""
+    import os
+    import sys
+    sys.path.insert(0, "src")
+    from repro.launch.hostdev import force_host_device_count
+    force_host_device_count(4)        # before jax's backend comes up
+    import json
+    import jax
+    from repro.configs import registry
+    from repro.configs.reduce import reduce_config
+    from repro.launch.mesh import make_mesh
+    from repro.train.trainer import Trainer
+
+    rcfg = reduce_config(registry.get_config("gpt2_nanogpt"))
+    mesh = make_mesh((1, 4), ("data", "model"))
+    tr = Trainer(rcfg, mesh=mesh, seed=0)
+    rep = tr.train(2, log_every=0, probe=False)
+    flat = jax.tree_util.tree_flatten_with_path(tr.params["mid"])[0]
+    solo = Trainer(rcfg, seed=0).train(1, log_every=0, probe=False)
+    print(json.dumps({
+        "devices": jax.device_count(),
+        "losses": rep.losses, "solo_loss": solo.losses[0],
+        "mid_specs": [list(map(str, leaf.sharding.spec))
+                      for _, leaf in flat],
+        "opt_mid_specs": [list(map(str, leaf.sharding.spec)) for leaf in
+                          jax.tree.leaves(tr.opt_state["m"]["mid"])]}))
+""")
+
+
+def test_trainer_places_trunk_on_model_axis_under_mesh():
+    """Layer-parallel training on a (data=1, model=4) mesh of 4 host
+    devices (the CPU rehearsal of ``chip_smoke.py --four-chips``): every
+    stacked trunk leaf, and its optimizer moment, carries 'model' on the
+    layer axis after two steps, and the first loss equals the unsharded
+    trainer's from the same seed."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)        # the script pins its own device count
+    r = subprocess.run([sys.executable, "-c", _MESH_TRAIN_SCRIPT],
+                       capture_output=True, text=True, cwd=os.getcwd(),
+                       env=env, timeout=600)
+    assert r.returncode == 0, r.stderr[-3000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    assert out["devices"] == 4
+    assert np.isfinite(out["losses"]).all()
+    np.testing.assert_allclose(out["losses"][0], out["solo_loss"], rtol=1e-5)
+    for specs in (out["mid_specs"], out["opt_mid_specs"]):
+        assert specs and all(s[0] == "model" for s in specs), specs
+
+
+@pytest.mark.parametrize("from_env", [False, True])
+def test_compile_cache_env_or_fixed_checkout_path(monkeypatch, tmp_path,
+                                                  from_env):
+    """JAX_COMPILATION_CACHE_DIR, where set, is left to JAX and no other
+    cache is set; otherwise the cache is <checkout>/.jax_cache."""
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = compile_cache.enable_compile_cache()
+        now = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    if from_env:
+        assert got == str(tmp_path) and now == before
+    else:
+        checkout = compile_cache.CACHE_DIR.parent
+        assert (checkout / "src" / "repro" / "launch").is_dir()
+        assert got == now == str(checkout / ".jax_cache")
 
 
 def test_train_cli_reduced():
