@@ -51,7 +51,8 @@ def main():
     report = trainer.train(args.steps, ckpt_every=max(args.steps // 4, 1),
                            log_every=25)
 
-    print(f"\nsteps/sec: {report.steps_per_sec:.2f}")
+    print(f"\nsteps/sec: {report.steps_per_sec:.2f} (compile-free steps; "
+          f"{report.compiles} compiles)")
     if report.switched_at is not None:
         print(f"adaptive controller switched LP->serial at step "
               f"{report.switched_at} (paper Fig. 4/5 behavior)")

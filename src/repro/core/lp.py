@@ -12,6 +12,7 @@ logical "layers" axis shards the solve over the mesh's "model" axis.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Dict, Optional, Tuple
@@ -76,6 +77,7 @@ def make_adj_step(static: LPStatic, extra: Extra) -> mgrit.StepFn:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("trunk_forward")
 def _forward_solve(static: LPStatic, stacked, z0, extra, iters: int):
     step = make_fwd_step(static, extra)
     if iters <= 0:
@@ -90,26 +92,33 @@ def _forward_solve(static: LPStatic, stacked, z0, extra, iters: int):
 def _adjoint_solve(static: LPStatic, stacked, states, lamN, extra,
                    iters: int):
     """Solve the adjoint backward from lam_N. Returns (rev_lam, lam0, norms)
-    with rev_lam[n] = lambda_{n+1} (the multiplier hitting layer n's output)."""
+    with rev_lam[n] = lambda_{n+1} (the multiplier hitting layer n's output).
+
+    With V-cycles (``iters > 0``) the solve is named ``trunk_adjoint`` in the
+    trace; the serial sweep at ``iters=0`` is the plain backward, unnamed."""
     rev = lambda a: jnp.flip(a, axis=0)
-    adj_stacked = {
-        "params": jax.tree.map(rev, stacked["params"]),
-        "gate": rev(stacked["gate"]),
-        "z": rev(states),
-    }
-    step = make_adj_step(static, extra)
-    if iters <= 0:
-        mu_states, mu_T = mgrit.serial_solve(step, adj_stacked, lamN,
-                                             static.mgrit.h)
-        norms = jnp.zeros((1,), jnp.float32)
-    else:
-        mu_states, mu_T, norms = mgrit.mgrit_solve(step, adj_stacked, lamN,
-                                                   static.spec(iters))
-    # mu_states[m] = lambda_{N-m}; layer n consumes lambda_{n+1} = mu[N-1-n]
-    rev_lam = rev(mu_states)
+    scope = jax.named_scope("trunk_adjoint") if iters > 0 \
+        else contextlib.nullcontext()
+    with scope:
+        adj_stacked = {
+            "params": jax.tree.map(rev, stacked["params"]),
+            "gate": rev(stacked["gate"]),
+            "z": rev(states),
+        }
+        step = make_adj_step(static, extra)
+        if iters <= 0:
+            mu_states, mu_T = mgrit.serial_solve(step, adj_stacked, lamN,
+                                                 static.mgrit.h)
+            norms = jnp.zeros((1,), jnp.float32)
+        else:
+            mu_states, mu_T, norms = mgrit.mgrit_solve(
+                step, adj_stacked, lamN, static.spec(iters))
+        # mu_states[m] = lambda_{N-m}; layer n consumes lambda_{n+1} = mu[N-1-n]
+        rev_lam = rev(mu_states)
     return rev_lam, mu_T, norms
 
 
+@jax.named_scope("trunk_param_grads")
 def _param_grads(static: LPStatic, stacked, states, rev_lam, extra):
     """Per-layer gradients g_theta_n = h*gate_n*(dF/dtheta_n)^T lambda_{n+1}
     and the summed extra-input cotangent — fully layer-parallel (vmap)."""

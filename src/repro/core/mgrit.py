@@ -221,24 +221,26 @@ def _vcycle(step_fn: StepFn, stacked, z0, states, zT, g, spec: MGRITSpec,
     resnorm = jnp.sqrt(jnp.sum(jnp.square(r.astype(jnp.float32))))
 
     # ---- coarse grid (FAS): u_{j+1} = Phi_c(u_j) + g_c[j] ----
-    coarse = coarse_restrict(stacked, cf)
-    h_c = h * cf
-    # replicate the coarse problem (the paper's serial coarse solve)
-    u0_rep = logical_constraint(u0, (None,) + spec.znames) \
-        if lspec.shard else u0
-    phi_c_u0 = jax.vmap(lambda p, u: step_fn(p, u, h_c))(coarse, u0_rep[:-1])
-    g_c = W - phi_c_u0                                             # (J, ...)
-    if lspec.shard:
-        g_c = logical_constraint(g_c, (None,) + spec.znames)
+    # named ``mgrit_coarse`` in the trace: the serial part of the solve
+    with jax.named_scope("mgrit_coarse"):
+        coarse = coarse_restrict(stacked, cf)
+        h_c = h * cf
+        # replicate the coarse problem (the paper's serial coarse solve)
+        u0_rep = logical_constraint(u0, (None,) + spec.znames) \
+            if lspec.shard else u0
+        phi_c_u0 = jax.vmap(lambda p, u: step_fn(p, u, h_c))(coarse,
+                                                             u0_rep[:-1])
+        g_c = W - phi_c_u0                                         # (J, ...)
+        if lspec.shard:
+            g_c = logical_constraint(g_c, (None,) + spec.znames)
 
-    if level + 1 >= spec.levels - 1 or J % cf != 0:
-        # exact coarsest solve: serial forward substitution
-        cs, czT = serial_solve(step_fn, coarse, z0, h_c, g=g_c)
-        u_new = jnp.concatenate([cs, czT[None]], axis=0)
-    else:
-        cs0 = u0_rep[:-1]
-        cs, czT, _ = _vcycle(step_fn, coarse, z0, cs0, u0_rep[-1], g_c,
-                             spec, level + 1, h_c)
+        if level + 1 >= spec.levels - 1 or J % cf != 0:
+            # exact coarsest solve: serial forward substitution
+            cs, czT = serial_solve(step_fn, coarse, z0, h_c, g=g_c)
+        else:
+            cs0 = u0_rep[:-1]
+            cs, czT, _ = _vcycle(step_fn, coarse, z0, cs0, u0_rep[-1], g_c,
+                                 spec, level + 1, h_c)
         u_new = jnp.concatenate([cs, czT[None]], axis=0)
 
     # ---- correct C-points and final F-relax (interpolation) ----
@@ -283,9 +285,10 @@ def mgrit_solve(step_fn: StepFn, stacked, z0, spec: MGRITSpec,
     chunked = _chunk(stacked, J, cf)
 
     if init_states is None:
-        coarse = coarse_restrict(stacked, cf)
-        cs, czT = serial_solve(step_fn, coarse, z0, spec.h * cf)
-        Zc0 = _constrain(cs, spec, ("layers",))
+        with jax.named_scope("mgrit_coarse"):
+            coarse = coarse_restrict(stacked, cf)
+            cs, czT = serial_solve(step_fn, coarse, z0, spec.h * cf)
+            Zc0 = _constrain(cs, spec, ("layers",))
         U = _f_relax(step_fn, chunked, Zc0, None, spec, spec.h)
         states = U.reshape((N,) + U.shape[2:])
         zT = czT

@@ -62,7 +62,8 @@ def main(argv=None):
                            log_every=10)
     print(f"done: {len(report.losses)} steps, "
           f"final loss {report.losses[-1]:.4f}, "
-          f"{report.steps_per_sec:.2f} steps/s, "
+          f"{report.steps_per_sec:.2f} steps/s over compile-free steps, "
+          f"{report.compiles} compiles, "
           f"switched_at={report.switched_at}")
     return 0
 

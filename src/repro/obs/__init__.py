@@ -1,5 +1,4 @@
-"""Serve-layer observability: metrics registry, lifecycle tracing,
-profiler hooks.
+"""Observability: metrics registry, lifecycle tracing, profiler hooks.
 
 One :class:`Observability` bundle per engine threads three host-side,
 hot-path-cheap surfaces through the serve stack (docs/observability.md):
@@ -11,6 +10,11 @@ hot-path-cheap surfaces through the serve stack (docs/observability.md):
   exported as Chrome/Perfetto trace-event JSON.
 - :mod:`repro.obs.profile` — compile-event counters around every jitted
   serve callable + opt-in ``jax.profiler`` span annotations.
+
+The trainer uses :mod:`repro.obs.profile` alone: its step spans are
+always-on ``jax.profiler`` annotations and its jitted steps count their
+traces into ``Trainer.compile_counts`` (docs/observability.md,
+"Training").
 
 Everything is append-only host work — no device sync is ever introduced
 on the jitted path — and ``Observability(enabled=False)`` collapses the

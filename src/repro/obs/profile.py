@@ -6,12 +6,14 @@ once per XLA trace, so wrapping the pre-jit callable with
 :func:`count_traces` counts compilations exactly — zero cost on cached
 calls beyond one dict increment at trace time. Every jitted serve
 callable (backend step/verify, draft prefill/wave, dense decode) wraps
-itself into its backend's ``compile_counts`` dict;
+itself into its backend's ``compile_counts`` dict, and the trainer's
+steps and probe into ``Trainer.compile_counts``;
 :func:`compiles_per_callable` is the derived gauge the registry exposes
 (``engine.compiles_per_callable``) — a recompile leak shows up as this
 number creeping above the expected O(log max_len) bucket count.
 
-**Profiler spans** are opt-in (``REPRO_PROFILE=1`` or an explicit flag):
+**Profiler spans** are opt-in for serving (``REPRO_PROFILE=1`` or an
+explicit flag) and always on in the trainer (an explicit ``True``):
 :func:`span_factory` returns a ``name -> context manager`` callable that
 is a shared no-op ``nullcontext`` when disabled (nothing allocated per
 call) and ``jax.profiler.TraceAnnotation`` when enabled, so the jitted
@@ -30,16 +32,26 @@ from typing import Callable, Dict
 
 _NULL = contextlib.nullcontext()
 
+# What the training step names (docs/observability.md, "Training"): the
+# device scopes of its phases, and the host spans of each step in the
+# order they open (``train.probe`` and ``train.ckpt`` only when they run).
+TRAIN_SCOPES = ("trunk_forward", "trunk_adjoint", "mgrit_coarse",
+                "trunk_param_grads", "optimizer_update", "mgrit_probe")
+TRAIN_SPANS = ("train.step", "train.input", "train.h2d", "train.probe",
+               "train.dispatch", "train.wait", "train.ckpt")
+
 
 def count_traces(name: str, fn: Callable, counts: Dict[str, int]) \
         -> Callable:
     """Wrap a pre-jit callable so each XLA trace of it increments
-    ``counts[name]`` (the body only runs when jit traces)."""
+    ``counts[name]`` (the body only runs when jit traces). The wrapper
+    keeps ``fn``'s name, so the jitted program is named as without it."""
     counts.setdefault(name, 0)
 
     def traced(*args):
         counts[name] = counts.get(name, 0) + 1
         return fn(*args)
+    traced.__name__ = traced.__qualname__ = getattr(fn, "__name__", name)
     return traced
 
 

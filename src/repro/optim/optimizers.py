@@ -82,6 +82,7 @@ def _freeze_structural(params, grads):
     return frozen, mask
 
 
+@jax.named_scope("optimizer_update")
 def apply_updates(cfg: OptimizerConfig, params, grads, state
                   ) -> Tuple[Any, Any, Any]:
     """Returns (new_params, new_state, metrics)."""

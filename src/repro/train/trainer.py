@@ -6,7 +6,13 @@
     and switch LP -> serial when it crosses 1 (Fig. 4 green curves);
   * fault tolerance: periodic atomic checkpoints, resume-from-latest,
     emergency checkpoint on exception;
-  * straggler watch: EWMA of step wall-time, slow steps logged.
+  * straggler watch: EWMA of step wall-time, slow steps logged;
+  * tracing: every step emits host spans (``train.step`` around
+    ``train.input``, ``train.h2d``, ``train.probe``, ``train.dispatch``,
+    ``train.wait``, ``train.ckpt``) as ``jax.profiler.TraceAnnotation``s,
+    which record only inside a profiler session, on the device trace's
+    clock; ``compile_counts`` counts the XLA traces of each jitted
+    function (docs/observability.md).
 
 The LP and serial steps are two separately jitted functions; switching is a
 host-side decision (it happens once per run, like the paper's). Under a
@@ -31,6 +37,7 @@ from repro.data.pipeline import make_pipeline, shard_batch
 from repro.launch import steps as steps_mod
 from repro.models import transformer
 from repro.models.blocks import block_kind
+from repro.obs import profile
 from repro.optim import optimizers
 from repro.train import checkpoint as ckpt_mod
 
@@ -41,7 +48,8 @@ class TrainReport:
     mode_trace: List[str]
     controller_history: List
     switched_at: Optional[int]
-    steps_per_sec: float
+    steps_per_sec: float       # over the call's compile-free steps
+    compiles: int              # XLA traces of the jitted functions
 
 
 class Trainer:
@@ -62,6 +70,11 @@ class Trainer:
         self._steps: Dict[str, Callable] = {}
         self._probe_fn = None
         self._ewma_dt = None
+        self.compile_counts: Dict[str, int] = {"lp": 0, "serial": 0,
+                                               "probe": 0}
+        # always on: a TraceAnnotation records only inside a profiler
+        # session, and outside one costs about a microsecond of host time
+        self.span = profile.span_factory(True)
 
         if ckpt_dir:
             restored = ckpt_mod.restore(ckpt_dir, self.params,
@@ -85,7 +98,9 @@ class Trainer:
             if mode == "serial":
                 rcfg = rcfg.replace(
                     mgrit=dataclasses.replace(rcfg.mgrit, enabled=False))
-            fn = steps_mod.make_train_fn(rcfg, self.mesh)
+            fn = profile.count_traces(
+                mode, steps_mod.make_train_fn(rcfg, self.mesh),
+                self.compile_counts)
             if self._shardings is None:
                 self._steps[mode] = jax.jit(fn, donate_argnums=(0, 1))
             else:
@@ -113,6 +128,8 @@ class Trainer:
         from repro.models.layers import rope_freqs
         from repro.models.transformer import _embed_inputs, _serial_buffer
 
+        # the phase scopes of lp_diagnose's solves nest inside this one
+        @jax.named_scope("mgrit_probe")
         def run(params, batch):
             z = _embed_inputs(params, batch, cfg)
             rope = None if kind in ("mamba1", "mamba2") else rope_freqs(
@@ -128,44 +145,59 @@ class Trainer:
                 fwd_iters=fwd_it, bwd_iters=bwd_it)
 
         if self._probe_fn is None:
-            self._probe_fn = jax.jit(run)
+            self._probe_fn = jax.jit(
+                profile.count_traces("probe", run, self.compile_counts))
         return self._probe_fn(self.params, batch)
 
     def train(self, num_steps: int, ckpt_every: int = 0,
               log_every: int = 50, probe: bool = True) -> TrainReport:
         losses, modes = [], []
-        t_start = time.time()
+        span = self.span
+        compiles, clean_s, n_clean = 0, 0.0, 0
         try:
             for _ in range(num_steps):
-                batch = shard_batch(self.pipeline.batch_at(self.step),
-                                    self.mesh, self.rcfg)
-                mode = self.controller.state.mode
-                t0 = time.time()
+                t0 = time.perf_counter()
+                n0 = sum(self.compile_counts.values())
+                with span("train.step"):
+                    with span("train.input"):
+                        batch = self.pipeline.batch_at(self.step)
+                    with span("train.h2d"):
+                        batch = shard_batch(batch, self.mesh, self.rcfg)
+                    mode = self.controller.state.mode
 
-                if probe and self.controller.should_probe(self.step):
-                    res = self._probe(batch)
-                    if res is not None:
-                        fwd_norms, bwd_norms = res
-                        action = self.controller.observe(
-                            self.step, np.asarray(fwd_norms),
-                            np.asarray(bwd_norms))
-                        if action == "switched":
-                            mode = "serial"
+                    probed = probe and self.controller.should_probe(
+                        self.step)
+                    if probed:
+                        with span("train.probe"):
+                            res = self._probe(batch)
+                            if res is not None:
+                                fwd_norms, bwd_norms = res
+                                action = self.controller.observe(
+                                    self.step, np.asarray(fwd_norms),
+                                    np.asarray(bwd_norms))
+                                if action == "switched":
+                                    mode = "serial"
 
-                fn = self._step_fn(mode)
-                self.params, self.opt_state, metrics = fn(
-                    self.params, self.opt_state, batch)
-                dt = time.time() - t0
-                self._ewma_dt = dt if self._ewma_dt is None else \
-                    0.9 * self._ewma_dt + 0.1 * dt
-                if dt > 3.0 * self._ewma_dt:
-                    print(f"[straggler] step {self.step} took {dt:.2f}s "
-                          f"(ewma {self._ewma_dt:.2f}s)")
-                losses.append(float(metrics["loss"]))
-                modes.append(mode)
-                self.step += 1
-                if ckpt_every and self.step % ckpt_every == 0:
-                    self._save()
+                    fn = self._step_fn(mode)
+                    with span("train.dispatch"):
+                        self.params, self.opt_state, metrics = fn(
+                            self.params, self.opt_state, batch)
+                    with span("train.wait"):
+                        losses.append(float(metrics["loss"]))
+                    modes.append(mode)
+                    self.step += 1
+                    saved = bool(ckpt_every) and self.step % ckpt_every == 0
+                    if saved:
+                        with span("train.ckpt"):
+                            self._save()
+                dt = time.perf_counter() - t0
+                traced = sum(self.compile_counts.values()) - n0
+                compiles += traced
+                if not traced:
+                    clean_s += dt
+                    n_clean += 1
+                    if not (probed or saved):
+                        self._watch(dt)
                 if log_every and self.step % log_every == 0:
                     print(f"step {self.step} [{mode}] "
                           f"loss={losses[-1]:.4f}")
@@ -173,12 +205,21 @@ class Trainer:
             if self.ckpt_dir:
                 self._save(tag="emergency")
             raise
-        dt_total = time.time() - t_start
         return TrainReport(
             losses=losses, mode_trace=modes,
             controller_history=list(self.controller.state.history),
             switched_at=self.controller.state.step_of_switch,
-            steps_per_sec=len(losses) / max(dt_total, 1e-9))
+            steps_per_sec=n_clean / clean_s if clean_s > 0 else 0.0,
+            compiles=compiles)
+
+    def _watch(self, dt: float):
+        """Straggler watch over compile-free steps that neither probe nor
+        checkpoint."""
+        self._ewma_dt = dt if self._ewma_dt is None else \
+            0.9 * self._ewma_dt + 0.1 * dt
+        if dt > 3.0 * self._ewma_dt:
+            print(f"[straggler] step {self.step - 1} took {dt:.2f}s "
+                  f"(ewma {self._ewma_dt:.2f}s)")
 
     def _save(self, tag: str = ""):
         ckpt_mod.save(self.ckpt_dir, self.step, self.params, self.opt_state,
