@@ -8,7 +8,9 @@ Train: ``gpt2_nanogpt`` at full width (20 layers, d 768, vocab 50304) on
 batches of 4 x 1024 tokens, through ``Trainer`` from one seed: serial
 steps, then layer-parallel steps with the config's MGRIT (cf 4, serial
 forward, one backward V-cycle, 2+2 buffer layers) and the adaptive
-indicator probe. Every loss is finite, the first LP loss equals the first
+indicator probe. Every attention call the steps trace takes the fused
+flash-attention kernels (``repro.obs.profile.ATTENTION_PATHS``; the dense
+core under ``--four-chips``' mesh), every loss is finite, the first LP loss equals the first
 serial loss within bf16 tolerance (with ``fwd_iters=0`` the LP forward is
 the serial one), and neither run's loss rises.
 
@@ -140,6 +142,7 @@ def print_device_memory(devices):
 def train_phase(rcfg, seed: int, mesh=None, steps: int = TRAIN_STEPS):
     """Serial then layer-parallel training from one seed; returns the LP
     trainer (for placement checks)."""
+    from repro.obs import profile
     from repro.train.trainer import Trainer
     serial_cfg = rcfg.replace(
         mgrit=dataclasses.replace(rcfg.mgrit, enabled=False))
@@ -147,7 +150,15 @@ def train_phase(rcfg, seed: int, mesh=None, steps: int = TRAIN_STEPS):
     for name, cfg, probe in (("serial", serial_cfg, False),
                              ("lp", rcfg, True)):
         trainer = Trainer(cfg, mesh=mesh, seed=seed)
+        before = dict(profile.ATTENTION_PATHS)
         losses, times, modes = run_steps(trainer, steps, probe)
+        paths = {p: n - before[p] for p, n in profile.ATTENTION_PATHS.items()}
+        print(f"train[{name}]: attention calls traced by path {paths}")
+        # one device: every call on the fused kernels; a larger mesh keeps
+        # the dense core (kernels.ops.flash_attention_mode)
+        core = "fused" if mesh is None or mesh.size == 1 else "dense"
+        check(paths[core] > 0 and sum(paths.values()) == paths[core],
+              f"{name}: an attention call left the {core} core: {paths}")
         runs[name] = losses
         warm = times[1:]
         print(f"train[{name}]: losses {[round(x, 5) for x in losses]} "

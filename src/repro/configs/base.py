@@ -223,6 +223,8 @@ class RunConfig:
     optimizer: OptimizerConfig = OptimizerConfig()
     shape: ShapeConfig = SHAPES[0]
     sharding: ShardingConfig = ShardingConfig()
+    # the flash-attention kernels in interpret mode on the CPU (tests); a
+    # TPU takes them whatever this says (kernels.ops.flash_attention_mode)
     use_pallas: bool = False
     remat: bool = True           # activation checkpointing in serial path
     # gradient-accumulation microbatches (bounds live MGRIT state memory)

@@ -9,7 +9,9 @@ caller remembered to pass the flag; now an unspecified mode always asks
 Two tiers of dispatch:
 
 * The training-side kernels (``flash_attention``/``rmsnorm``/``ssm_scan``)
-  keep their boolean contract: compiled on TPU, interpret on CPU.
+  keep their boolean contract: compiled on TPU, interpret on CPU. Whether
+  the model's attention core takes ``flash_attention`` at all is
+  :func:`flash_attention_mode`.
 * The paged-decode kernels (``paged_attention``/``paged_ssm_update``/
   ``topk_topp_mask``) are three-way — mode "pallas" (compiled Mosaic, the
   TPU default), "interpret" (the same Pallas body executed per grid cell
@@ -60,9 +62,40 @@ def kernel_mode(mode: str | None = None) -> str:
     return mode
 
 
+def _mesh_devices() -> int:
+    """Devices of the mesh the current trace runs under: the logical-axis
+    rules' mesh (``repro.parallel.sharding.axis_rules``) or JAX's own."""
+    from repro.parallel.sharding import current_rules
+    mesh, _ = current_rules()
+    return max(mesh.size if mesh is not None else 1,
+               jax.sharding.get_abstract_mesh().size)
+
+
+def flash_attention_mode(use_pallas: bool = False) -> str | None:
+    """How the model's attention core runs the flash-attention kernels, or
+    None where it keeps its jnp cores (single owner, as :func:`kernel_mode`
+    is for the paged kernels).
+
+    "pallas" (compiled) on a TPU, or on the CPU under
+    ``REPRO_KERNEL_MODE=pallas`` (a compile for a described chip);
+    "interpret" on the CPU where ``use_pallas`` asks for it (tests); None
+    otherwise. None too under a mesh of more than one device: GSPMD cannot
+    partition a Mosaic kernel (it would need a ``shard_map`` around it,
+    which the attention core does not have), so a sharded step keeps the
+    dense core.
+    """
+    if _mesh_devices() > 1:
+        return None
+    if (jax.default_backend() == "tpu"
+            or os.environ.get("REPRO_KERNEL_MODE") == "pallas"):
+        return "pallas"
+    return "interpret" if use_pallas else None
+
+
 @functools.partial(jax.jit, static_argnames=("causal", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, interpret=None):
-    """q: (B, S, H, hd); k/v: (B, S, Hkv, hd) — model layout."""
+    """q: (B, S, H, hd); k/v: (B, S, Hkv, hd) — model layout.
+    Differentiable through the kernels' own backward."""
     interpret = _interpret_default() if interpret is None else interpret
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)

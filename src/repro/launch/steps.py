@@ -1,7 +1,8 @@
-"""Jitted step builders: train_step / prefill_step / serve_step with
+"""Jitted step builders: train_step / probe / prefill_step / serve_step with
 GSPMD shardings derived from the config's logical-axis rules."""
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional
 
 import jax
@@ -9,7 +10,10 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import RunConfig
+from repro.core import lp as lp_mod
 from repro.models import transformer
+from repro.models.blocks import block_kind
+from repro.models.layers import rope_freqs
 from repro.optim import optimizers
 from repro.parallel import params as pshard
 from repro.parallel.sharding import axis_rules
@@ -58,6 +62,43 @@ def make_train_fn(rcfg: RunConfig, mesh: Optional[Mesh]):
         return params2, opt_state2, metrics
 
     return train_step
+
+
+def make_probe_fn(rcfg: RunConfig, mesh: Optional[Mesh], fwd_iters: int,
+                  bwd_iters: int):
+    """Returns probe(params, batch) -> ``lp_diagnose``'s output: the
+    paper's indicator probe, the trunk's MGRIT solves at the given
+    iteration counts after the embedding and the opening buffer layers,
+    traced under the same logical-axis rules as the train step."""
+    cfg = rcfg.model
+    kind = block_kind(cfg)
+    static = lp_mod.LPStatic(
+        cfg=cfg,
+        mgrit=dataclasses.replace(rcfg.mgrit, fwd_iters=fwd_iters,
+                                  bwd_iters=bwd_iters),
+        kind=kind, causal=cfg.family != "encoder")
+
+    # the phase scopes of lp_diagnose's solves nest inside this one
+    @jax.named_scope("mgrit_probe")
+    def probe(params, batch):
+        ctx = axis_rules(mesh, rcfg.sharding) if mesh is not None else \
+            _nullctx()
+        with ctx:
+            z = transformer._embed_inputs(params, batch, cfg)
+            rope = None if kind in ("mamba1", "mamba2") else rope_freqs(
+                cfg.resolved_head_dim, cfg.rope_theta,
+                jnp.arange(z.shape[1], dtype=jnp.int32))
+            z = transformer._serial_buffer(params.get("open"), z, cfg,
+                                           kind=kind, causal=static.causal,
+                                           rope=rope)
+            extra = {"rope": rope} if rope is not None else {}
+            return lp_mod.lp_diagnose(
+                static, params["mid"], z, extra,
+                seed_ct=lambda zT: jnp.ones_like(zT)
+                / jnp.asarray(zT.size, zT.dtype),
+                fwd_iters=fwd_iters, bwd_iters=bwd_iters)
+
+    return probe
 
 
 def make_prefill_fn(rcfg: RunConfig, mesh: Optional[Mesh]):

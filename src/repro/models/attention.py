@@ -5,9 +5,12 @@ Layout conventions:
   q after projection (B, S, H, hd)      logical ("batch","seq","heads","head_dim")
   kv cache           (B, S_max, Hkv, hd) logical ("batch","kv_seq","kv_heads","head_dim")
 
-The dense attention math lives in ``dot_attention``; when
-``use_pallas=True`` the fused Pallas flash-attention kernel
-(:mod:`repro.kernels.ops`) is used instead for the self-attention hot spot.
+The dense attention math lives in ``dot_attention``. Self-attention
+without a cache takes the fused Pallas flash-attention kernels
+(:mod:`repro.kernels.flash_attention`, forward and backward) where
+:func:`repro.kernels.ops.flash_attention_mode` says they run: on a TPU
+with one device, or with ``use_pallas=True`` (interpret mode on the CPU);
+see ``_attention_path``.
 """
 from __future__ import annotations
 
@@ -17,7 +20,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import flash_attention as fa
+from repro.kernels import ops as kops
 from repro.models.layers import apply_rope, dense_init, preln_output_scale, rms_norm, rope_freqs
+from repro.obs import profile
 from repro.parallel.sharding import logical_constraint
 
 
@@ -140,6 +146,23 @@ def chunked_attention(q, k, v, *, causal: bool, q_block: int = 512,
 ATTN_CHUNK_THRESHOLD = 8192
 
 
+def _attention_path(q, k, cfg: ModelConfig, *, cache, xa,
+                    fuse: bool) -> str:
+    """Which attention core a call takes: ``fused`` (the flash-attention
+    kernels, forward and backward) for self-attention without a cache over
+    a length the kernel blocks divide, where ``fuse`` (the kernels run
+    here: :func:`repro.kernels.ops.flash_attention_mode`); else
+    ``chunked`` from ``cfg.attn_chunk`` tokens, else ``dense``."""
+    S, hd = q.shape[1], q.shape[3]
+    if (fuse and cache is None and xa is None and S == k.shape[1]
+            and hd <= 128 and fa.block_size(S) is not None):
+        return "fused"
+    if (cache is None and S >= (cfg.attn_chunk or ATTN_CHUNK_THRESHOLD)
+            and S == k.shape[1] and S % 512 == 0):
+        return "chunked"
+    return "dense"
+
+
 def attention_apply(params, x, cfg: ModelConfig, *, causal: bool,
                     rope=None, positions=None, xa=None, cache=None,
                     use_pallas: bool = False):
@@ -177,13 +200,15 @@ def attention_apply(params, x, cfg: ModelConfig, *, causal: bool,
         k, v = ck, cv
         q_offset = idx
 
+    mode = kops.flash_attention_mode(use_pallas)
+    path = _attention_path(q, k, cfg, cache=cache, xa=xa,
+                           fuse=mode is not None)
+    profile.count_attention(path)
     with jax.named_scope("attn_core"):
-        if use_pallas and cache is None and xa is None and q.shape[1] > 1:
-            from repro.kernels import ops as kops
-            out = kops.flash_attention(q, k, v, causal=causal)
-        elif (cache is None and q.shape[1] >= (cfg.attn_chunk
-                                               or ATTN_CHUNK_THRESHOLD)
-              and q.shape[1] == k.shape[1] and q.shape[1] % 512 == 0):
+        if path == "fused":
+            out = kops.flash_attention(q, k, v, causal=causal,
+                                       interpret=mode == "interpret")
+        elif path == "chunked":
             out = chunked_attention(q, k, v, causal=causal and xa is None)
         else:
             out = dot_attention(q, k, v, causal=causal and xa is None,
@@ -289,7 +314,6 @@ def paged_attention_apply(params, x, cfg: ModelConfig, *, rope, pk, pv,
         B * S, *v.shape[2:]))
 
     if fused:
-        from repro.kernels import ops as kops
         with jax.named_scope("paged_attn_core_fused"):
             out = kops.paged_attention(q, pk_flat.reshape(pk.shape),
                                        pv_flat.reshape(pv.shape),

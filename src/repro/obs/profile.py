@@ -12,6 +12,10 @@ steps and probe into ``Trainer.compile_counts``;
 (``engine.compiles_per_callable``) — a recompile leak shows up as this
 number creeping above the expected O(log max_len) bucket count.
 
+**The attention path counter** :data:`ATTENTION_PATHS` counts, at trace
+time, which core each attention call took (fused kernel, dense or
+chunked), so a run can show that every layer of a step took the kernel.
+
 **Profiler spans** are opt-in for serving (``REPRO_PROFILE=1`` or an
 explicit flag) and always on in the trainer (an explicit ``True``):
 :func:`span_factory` returns a ``name -> context manager`` callable that
@@ -39,6 +43,17 @@ TRAIN_SCOPES = ("trunk_forward", "trunk_adjoint", "mgrit_coarse",
                 "trunk_param_grads", "optimizer_update", "mgrit_probe")
 TRAIN_SPANS = ("train.step", "train.input", "train.h2d", "train.probe",
                "train.dispatch", "train.wait", "train.ckpt")
+
+
+# Attention calls by the core they took (``models.attention._attention_path``),
+# counted when a call is traced: each traced call site once (a layer under
+# vmap or scan once), however often the program runs. Read a difference
+# around a trace.
+ATTENTION_PATHS: Dict[str, int] = {"fused": 0, "dense": 0, "chunked": 0}
+
+
+def count_attention(path: str) -> None:
+    ATTENTION_PATHS[path] += 1
 
 
 def count_traces(name: str, fn: Callable, counts: Dict[str, int]) \

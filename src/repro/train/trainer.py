@@ -32,11 +32,9 @@ import numpy as np
 
 from repro.configs.base import RunConfig
 from repro.core.adaptive import AdaptiveController
-from repro.core import lp as lp_mod
 from repro.data.pipeline import make_pipeline, shard_batch
 from repro.launch import steps as steps_mod
 from repro.models import transformer
-from repro.models.blocks import block_kind
 from repro.obs import profile
 from repro.optim import optimizers
 from repro.train import checkpoint as ckpt_mod
@@ -112,41 +110,14 @@ class Trainer:
 
     def _probe(self, batch):
         """Paper's indicator probe: doubled iterations, measure rho."""
-        fwd_it, bwd_it = self.controller.probe_iters()
-        rcfg = self.rcfg
-        cfg = rcfg.model
-        kind = block_kind(cfg)
-        if cfg.family in ("hybrid",):
+        if self.rcfg.model.family in ("hybrid",):
             return None  # LP inapplicable; controller never probes anyway
-
-        static = lp_mod.LPStatic(
-            cfg=cfg,
-            mgrit=dataclasses.replace(rcfg.mgrit, fwd_iters=fwd_it,
-                                      bwd_iters=bwd_it),
-            kind=kind, causal=cfg.family != "encoder")
-
-        from repro.models.layers import rope_freqs
-        from repro.models.transformer import _embed_inputs, _serial_buffer
-
-        # the phase scopes of lp_diagnose's solves nest inside this one
-        @jax.named_scope("mgrit_probe")
-        def run(params, batch):
-            z = _embed_inputs(params, batch, cfg)
-            rope = None if kind in ("mamba1", "mamba2") else rope_freqs(
-                cfg.resolved_head_dim, cfg.rope_theta,
-                jnp.arange(z.shape[1], dtype=jnp.int32))
-            z = _serial_buffer(params.get("open"), z, cfg, kind=kind,
-                               causal=static.causal, rope=rope)
-            extra = {"rope": rope} if rope is not None else {}
-            return lp_mod.lp_diagnose(
-                static, params["mid"], z, extra,
-                seed_ct=lambda zT: jnp.ones_like(zT)
-                / jnp.asarray(zT.size, zT.dtype),
-                fwd_iters=fwd_it, bwd_iters=bwd_it)
-
         if self._probe_fn is None:
-            self._probe_fn = jax.jit(
-                profile.count_traces("probe", run, self.compile_counts))
+            fwd_it, bwd_it = self.controller.probe_iters()
+            self._probe_fn = jax.jit(profile.count_traces(
+                "probe", steps_mod.make_probe_fn(self.rcfg, self.mesh,
+                                                 fwd_it, bwd_it),
+                self.compile_counts))
         return self._probe_fn(self.params, batch)
 
     def train(self, num_steps: int, ckpt_every: int = 0,

@@ -35,7 +35,7 @@ SLOTS, PAGE, POOL_PAGES, TABLE = 8, 16, 256, 16
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -49,8 +49,13 @@ def one_chip():
                                             topology_name="v5e:2x2")
     except Exception as e:     # no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 def _compile(one_chip, fn, *shapes):
@@ -105,6 +110,85 @@ def test_flash_attention_compiles_at_gpt2_widths(one_chip):
                                                 interpret=False),
         shape, shape, shape)
     assert "tpu_custom_call" in hlo
+
+
+def test_flash_attention_backward_compiles_at_gpt2_widths(one_chip):
+    # the training shape: batch 4, 12 heads of 64, 1024 tokens; the
+    # gradient runs the forward (with its log-sum-exp) and both backward
+    # kernels
+    shape = ((4, 12, 1024, 64), BF16)
+
+    def grads(q, k, v, do):
+        _, vjp = jax.vjp(lambda q, k, v: fa.flash_attention_bhsd(
+            q, k, v, causal=True, interpret=False), q, k, v)
+        return vjp(do)
+
+    hlo = _compile(one_chip, grads, shape, shape, shape, shape)
+    assert hlo.count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_lp_programs_compile_with_their_attention_path(v5e, monkeypatch,
+                                                       chips):
+    """The layer-parallel train step and the trainer's indicator probe
+    compile for one described chip with every attention call on the fused
+    kernels, and for a (data 1, model 4) mesh over the described 2x2 with
+    none of them: GSPMD cannot partition a Mosaic kernel, so a sharded
+    program keeps the dense core. Small widths (two heads of 64, 256
+    tokens, a trunk of 8 layers): neither the path nor the partitioning
+    depends on the width."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+
+    from repro.configs import registry
+    from repro.configs.reduce import reduce_config
+    from repro.launch import steps as steps_mod
+    from repro.models import transformer
+    from repro.obs import profile
+    from repro.optim import optimizers
+
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "pallas")   # as on the chip
+    rcfg = reduce_config(registry.get_config("gpt2_nanogpt"), seq=256,
+                         batch=4)
+    rcfg = rcfg.replace(model=dataclasses.replace(
+        rcfg.model, d_model=128, n_heads=2, n_kv_heads=2, head_dim=64))
+    params = jax.eval_shape(lambda k: transformer.init_model(k, rcfg),
+                            jax.random.PRNGKey(0))
+    opt = jax.eval_shape(lambda p: optimizers.init_opt_state(
+        rcfg.optimizer, p), params)
+    batch = {k: jax.ShapeDtypeStruct((4, 256), I32)
+             for k in ("tokens", "labels")}
+    if chips == 1:
+        mesh = None
+        one = SingleDeviceSharding(v5e.devices[0])
+        step = jax.jit(steps_mod.make_train_fn(rcfg, mesh))
+        probe = jax.jit(steps_mod.make_probe_fn(rcfg, mesh, 2, 2))
+        params, opt, batch = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), (params, opt, batch))
+    else:
+        mesh = Mesh(np.array(v5e.devices[:4]).reshape(1, 4),
+                    ("data", "model"),
+                    axis_types=(AxisType.Auto, AxisType.Auto))
+        ps, os_, bs = steps_mod.shardings_for_train(rcfg, mesh, params, opt,
+                                                    batch)
+        step = jax.jit(steps_mod.make_train_fn(rcfg, mesh),
+                       in_shardings=(ps, os_, bs),
+                       out_shardings=(ps, os_, None))
+        probe = jax.jit(steps_mod.make_probe_fn(rcfg, mesh, 2, 2),
+                        in_shardings=(ps, bs))
+    for fn, args in ((step, (params, opt, batch)), (probe, (params, batch))):
+        before = dict(profile.ATTENTION_PATHS)
+        hlo = fn.lower(*args).compile().as_text()
+        taken = {p: n - before[p]
+                 for p, n in profile.ATTENTION_PATHS.items()}
+        if chips == 1:
+            assert taken["fused"] > 0 and taken["dense"] == 0, taken
+            assert "tpu_custom_call" in hlo
+        else:
+            assert taken["fused"] == 0 and taken["dense"] > 0, taken
+            assert "tpu_custom_call" not in hlo
 
 
 def test_rmsnorm_compiles_at_qwen3_width(one_chip):

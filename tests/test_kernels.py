@@ -87,3 +87,131 @@ def test_flash_attention_model_layout():
     expect = dot_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=2e-4, atol=2e-4)
+
+
+def _bhsd(x):
+    return x.transpose(0, 2, 1, 3)
+
+
+def _max_err(a, b):
+    return float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                 - b.astype(jnp.float32))))
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("H,Hkv,hd,layers", [
+    (4, 4, 64, 0),
+    (4, 2, 128, 0),                    # GQA
+    (4, 2, 64, 3),                     # under vmap over a layer axis
+])
+def test_flash_attention_vjp_matches_dense(causal, H, Hkv, hd, layers):
+    """The fused kernels' output and dQ, dK, dV match ``jax.vjp`` of
+    ``dot_attention`` on the same bf16 values in float32: 256 tokens in
+    2 x 2 blocks of 128, so the causal skip and the diagonal mask are
+    both crossed. ``layers`` maps the call over a leading layer axis, as
+    the layer-parallel solver's F-relaxation and parameter VJPs do."""
+    from repro.models.attention import dot_attention
+    B, S = 2, 256
+    lead = (layers,) if layers else ()
+    ks = jax.random.split(jax.random.PRNGKey(4), 4)
+    q = rand(ks[0], lead + (B, S, H, hd), jnp.bfloat16)
+    k = rand(ks[1], lead + (B, S, Hkv, hd), jnp.bfloat16)
+    v = rand(ks[2], lead + (B, S, Hkv, hd), jnp.bfloat16)
+    do = rand(ks[3], lead + (B, S, H, hd), jnp.bfloat16)
+
+    def fused(q, k, v):
+        return _bhsd(flash_attention_bhsd(
+            _bhsd(q), _bhsd(k), _bhsd(v), causal=causal, q_block=128,
+            k_block=128, interpret=True))
+
+    def dense(q, k, v):
+        return dot_attention(q, k, v, causal=causal)
+
+    if layers:
+        fused, dense = jax.vmap(fused), jax.vmap(dense)
+    o, vjp = jax.vjp(fused, q, k, v)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v, do)]
+    o_ref, vjp_ref = jax.vjp(dense, *f32[:3])
+    assert o.dtype == jnp.bfloat16
+    for got, want in zip((o,) + vjp(do), (o_ref,) + vjp_ref(f32[3])):
+        assert got.shape == want.shape
+        scale = float(jnp.max(jnp.abs(want)))
+        assert _max_err(got, want) <= 2e-2 * scale, (_max_err(got, want),
+                                                      scale)
+
+
+def test_attention_path_counter():
+    """``attention_apply`` takes the fused kernels for self-attention with
+    ``use_pallas`` over a length the blocks divide, and the dense core for a
+    cached call, a cross-attention call and a length off the blocks; the
+    counter in ``repro.obs.profile`` records each."""
+    from repro.configs import registry
+    from repro.configs.reduce import reduce_config
+    from repro.models.attention import (attention_apply, init_attention,
+                                        init_kv_cache)
+    from repro.obs import profile
+
+    cfg = reduce_config(registry.get_config("gpt2_nanogpt")).model
+    params = init_attention(jax.random.PRNGKey(5), cfg)
+    cross = init_attention(jax.random.PRNGKey(6), cfg, cross=True)
+
+    def path_of(fn, *args):
+        before = dict(profile.ATTENTION_PATHS)
+        jax.eval_shape(fn, *args)
+        taken = {p: n - before[p] for p, n in profile.ATTENTION_PATHS.items()
+                 if n != before[p]}
+        assert list(taken.values()) == [1], taken
+        return next(iter(taken))
+
+    def x(S):
+        return jnp.zeros((2, S, cfg.d_model), jnp.float32)
+
+    def self_attn(x):
+        return attention_apply(params, x, cfg, causal=True,
+                               positions=jnp.arange(x.shape[1]),
+                               use_pallas=True)[0]
+
+    cache = init_kv_cache(cfg, 2, 64, 1)
+    cache = {"k": cache["k"][0], "v": cache["v"][0],
+             "index": cache["index"]}
+
+    def cached(x):
+        return attention_apply(params, x, cfg, causal=True,
+                               positions=jnp.arange(x.shape[1]),
+                               cache=cache, use_pallas=True)[0]
+
+    def cross_attn(x, xa):
+        return attention_apply(cross, x, cfg, causal=False, xa=xa,
+                               use_pallas=True)[0]
+
+    assert path_of(self_attn, x(128)) == "fused"
+    assert path_of(cached, x(8)) == "dense"
+    assert path_of(cross_attn, x(128), x(128)) == "dense"
+    assert path_of(self_attn, x(200)) == "dense"
+
+
+def test_flash_attention_mode_keeps_the_dense_core_under_a_mesh(
+        monkeypatch):
+    """The fused kernels run in interpret mode on the CPU where
+    ``use_pallas`` asks, compiled under ``REPRO_KERNEL_MODE=pallas`` (a
+    compile for a described chip), and not at all under a mesh of more
+    than one device, where GSPMD cannot partition a Mosaic kernel."""
+    from jax.sharding import AbstractMesh
+
+    from repro.configs.base import ShardingConfig
+    from repro.kernels import ops as kops
+    from repro.parallel.sharding import axis_rules
+
+    monkeypatch.delenv("REPRO_KERNEL_MODE", raising=False)
+    assert kops.flash_attention_mode(False) is None
+    assert kops.flash_attention_mode(True) == "interpret"
+    one = AbstractMesh((1, 1), ("data", "model"))
+    four = AbstractMesh((1, 4), ("data", "model"))
+    with axis_rules(one, ShardingConfig()):
+        assert kops.flash_attention_mode(True) == "interpret"
+    with axis_rules(four, ShardingConfig()):
+        assert kops.flash_attention_mode(True) is None
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "pallas")
+    assert kops.flash_attention_mode(False) == "pallas"
+    with axis_rules(four, ShardingConfig()):
+        assert kops.flash_attention_mode(False) is None

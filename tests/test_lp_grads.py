@@ -132,3 +132,40 @@ def test_fwd_residual_norms_exposed():
     norms = np.asarray(diag["fwd_norms"])
     assert norms.shape == (3,)
     assert np.all(np.isfinite(norms))
+
+
+def test_lp_step_through_fused_attention():
+    """The LP loss and gradients of a small GPT-2 with ``use_pallas``
+    (the flash-attention kernels in interpret mode, vmapped over the trunk
+    by the solver) match the dense attention core within bf16 tolerance,
+    and every attention call of the step takes the fused path."""
+    from repro.obs import profile
+    base = reduce_config(registry.get_config("gpt2_nanogpt"), seq=64)
+    base = dataclasses.replace(base, mgrit=dataclasses.replace(
+        base.mgrit, fwd_iters=0, bwd_iters=1))
+    fused = dataclasses.replace(base, use_pallas=True)
+    key = jax.random.PRNGKey(4)
+    params = transformer.init_model(key, base)
+    toks = jax.random.randint(jax.random.fold_in(key, 1), (2, 64), 0,
+                              base.model.vocab_size)
+    batch = {"tokens": toks, "labels": jnp.roll(toks, -1, axis=1)}
+
+    def loss_and_grad(rcfg):
+        return jax.jit(jax.value_and_grad(
+            lambda p: transformer.loss_fn(p, batch, rcfg, mode="lp")[0]
+        ))(params)
+
+    before = dict(profile.ATTENTION_PATHS)
+    lf, gf = loss_and_grad(fused)
+    taken = {p: n - before[p] for p, n in profile.ATTENTION_PATHS.items()}
+    assert taken["fused"] > 0 and taken["dense"] == 0, taken
+    ld, gd = loss_and_grad(base)
+    np.testing.assert_allclose(float(lf), float(ld), rtol=2e-3)
+    for a, b in zip(jax.tree.leaves(gf), jax.tree.leaves(gd)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        gap = np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30)
+        assert gap < 3e-2, (a.shape, gap)
+    fa, fd = np.asarray(_flat(gf)), np.asarray(_flat(gd))
+    cos = float(np.dot(fa, fd)
+                / (np.linalg.norm(fa) * np.linalg.norm(fd) + 1e-30))
+    assert cos > 0.9999, f"cosine {cos}"
