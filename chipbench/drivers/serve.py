@@ -7,7 +7,10 @@ request when it is due and drives ``Scheduler.step`` between arrivals;
 after the window closes it keeps stepping, without new arrivals, until
 every request has finished or the drain limit passes. Times are taken
 from when each request was due, so a stall's delay on later arrivals
-counts. ``--trace 1`` profiles ``trace_seconds`` in the middle of the
+counts. Every token's arrival is stamped when the step that made it
+returns, so the inter-token gaps of all requests make up the time per
+output token; the pages in use are sampled beside the queue's length.
+``--trace 1`` profiles ``trace_seconds`` in the middle of the
 window. Once the engine is freed the plain reference runs every sampled
 greedy request's prompt and served tokens and reads how far below its
 best logit each served token lies.
@@ -55,23 +58,51 @@ class CallLog:
         self.backend.prefill, self.backend.step = self.orig
 
 
-class QueueDepth:
-    """Time-weighted mean of a step function sampled inside [t0, end]."""
+class TimeWeighted:
+    """Time-weighted mean and peak of a step function sampled inside
+    [t0, end]."""
 
     def __init__(self, t0, end):
         self.t0, self.end = t0, end
-        self.t, self.v, self.area = t0, 0, 0.0
+        self.t, self.v, self.area, self.peak = t0, 0, 0.0, 0
 
     def sample(self, now, value):
-        now = min(now, self.end)
+        if now > self.end:
+            now, value = self.end, self.v
         if now > self.t:
             self.area += self.v * (now - self.t)
             self.t = now
         self.v = value
+        self.peak = max(self.peak, value)
 
     def mean(self):
         self.sample(self.end, self.v)
         return self.area / (self.end - self.t0)
+
+
+class TokenGaps:
+    """Every inter-token gap of the requests due in the window: after each
+    scheduler step, a request whose output grew by k tokens since the last
+    look adds k gaps of (now - last) / k; the first token counts from the
+    scheduler's own stamp."""
+
+    def __init__(self):
+        self.live, self.gaps = {}, []      # id -> [handle, tokens seen, time]
+
+    def add(self, handle):
+        self.live[id(handle)] = [handle, 0, 0.0]
+
+    def look(self, now):
+        for key, (h, seen, t) in list(self.live.items()):
+            n = len(h.out)
+            if n > seen:
+                if seen == 0:
+                    seen, t = 1, h.t_first
+                if n > seen:
+                    self.gaps += [(now - t) / (n - seen)] * (n - seen)
+                self.live[key] = [h, n, now]
+            if h.done:
+                del self.live[key]
 
 
 class Segment:
@@ -163,6 +194,9 @@ def run(run):
     vocab = program["model"]["vocab_size"]
     reqs = generator.open_loop(mix, run.seconds, run.seed, vocab)
     warm(engine, generator.step_shapes(mix, dep["max_len"], page), page)
+    n_pages = sched.alloc.n_pages
+    pool_bytes = sum(a.nbytes for a in jax.tree.leaves(sched.state))
+    weight_bytes = sum(a.nbytes for a in jax.tree.leaves(engine.backend.params))
     run.setup_done()
 
     # ---- measured window ----
@@ -171,7 +205,8 @@ def run(run):
     i, n = 0, len(reqs)
     t0 = time.perf_counter()
     end = t0 + run.seconds
-    depth = QueueDepth(t0, end)
+    depth, pages = TimeWeighted(t0, end), TimeWeighted(t0, end)
+    tokens = TokenGaps()
     seg = Segment(run, engine.backend,
                   t0 + (run.seconds - mix["trace_seconds"]) / 2,
                   mix["trace_seconds"]) if run.trace else None
@@ -184,8 +219,10 @@ def run(run):
             handles[i] = sched.submit_request(
                 r.prompt, r.max_new, None, temperature=r.temperature,
                 top_k=r.top_k, top_p=r.top_p, seed=r.seed)
+            tokens.add(handles[i])
             i += 1
         depth.sample(now, len(sched.queue))
+        pages.sample(now, n_pages - 1 - sched.alloc.n_free)
         if closed is None and now >= end:
             closed = sum(len(h.out) for h in handles if h is not None)
             at_close = (len(sched.queue),
@@ -194,7 +231,10 @@ def run(run):
                                    or now > end + mix["drain_limit_s"]):
             break
         busy = seg.step(sched, now) if seg else sched.step()
-        depth.sample(time.perf_counter(), len(sched.queue))
+        now = time.perf_counter()
+        tokens.look(now)
+        depth.sample(now, len(sched.queue))
+        pages.sample(now, n_pages - 1 - sched.alloc.n_free)
         if not busy:
             nxt = t0 + reqs[i].due if i < n else end
             time.sleep(max(0.0, min(nxt, end) - time.perf_counter()))
@@ -207,14 +247,17 @@ def run(run):
     good = [h for h in handles if h.done and h.error is None]
     ttft = [(h.t_first - (t0 + r.due)) * 1e3
             for h, r in zip(handles, reqs) if h.done and h.error is None]
-    tpot = [(h.t_done - h.t_first) / (len(h.out) - 1) * 1e3
-            for h in good if len(h.out) > 1]
+    tpot = [g * 1e3 for g in tokens.gaps]
     run.attempted, run.failed = n, len(failed)
     run.data.update(
-        kind="serve", ttft_p90_ms=common.percentile(ttft, 90),
+        kind="serve", ttft_p50_ms=common.percentile(ttft, 50),
+        ttft_p90_ms=common.percentile(ttft, 90),
         tpot_p90_ms=common.percentile(tpot, 90),
         serve_tokens_per_s=closed / run.seconds,
         queue_depth_mean=depth.mean(), queue_at_close=at_close[0],
+        pages_in_use_mean=pages.mean(), pages_in_use_peak=pages.peak,
+        page_bytes=pool_bytes / n_pages, weight_bytes=weight_bytes,
+        gaps=len(tpot), gaps_over_1s=sum(g > 1e3 for g in tpot),
         unfinished_at_close=at_close[1], drain_s=drain_s,
         late_p90_ms=common.percentile([x * 1e3 for x in late], 90),
         model=program["model"])
@@ -235,7 +278,7 @@ def run(run):
         sample.append(rest.pop(0))
     seqs = [(np.concatenate([h.prompt, np.asarray(h.out[:-1], np.int32)]),
              len(h.prompt), np.asarray(h.out, np.int32)) for h, _ in sample]
-    del engine, sched, handles, seg
+    del engine, sched, handles, seg, tokens
     gc.collect()
 
     # ---- the reference ----
@@ -247,6 +290,12 @@ def run(run):
     run.data["reference_s"] = time.perf_counter() - t_ref
     run.compare({"logit_gap": gap}, {"logit_gap": c_gap})
     run.check("compiles_in_window", compiles, 0)
+    run.note(f"pages in use: mean {pages.mean():.1f}, peak {pages.peak} "
+             f"of {n_pages - 1} ({pool_bytes / n_pages:.0f} bytes each); "
+             f"weights {weight_bytes} bytes")
+    run.note("ttft ms by request: " + " ".join(f"{x:.0f}" for x in ttft))
+    run.note(f"{len(tpot)} inter-token gaps, "
+             f"{run.data['gaps_over_1s']} over 1 s")
     run.note(f"{len(seqs)} requests, {n_tok} served tokens compared; "
              f"{len(good)}/{n} finished; generator late p90 "
              f"{run.data['late_p90_ms']} ms")

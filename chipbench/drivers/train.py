@@ -157,6 +157,13 @@ def run(run):
     if not ends:
         raise RuntimeError(f"no step finished inside {run.seconds} s")
     tps = len(ends) * tokens_per_step / (ends[-1] - t0)
+    step_ms = np.diff([t0] + ends) * 1e3
+    run.note(f"window: {len(ends)} steps, step ms p10 "
+             f"{np.percentile(step_ms, 10):.2f} p50 "
+             f"{np.median(step_ms):.2f} p90 {np.percentile(step_ms, 90):.2f}"
+             f" max {step_ms.max():.2f}, over 1.5 x p50: "
+             f"{int((step_ms > 1.5 * np.median(step_ms)).sum())}; input ms "
+             f"mean {np.mean(window_ms):.3f} max {np.max(window_ms):.3f}")
     run.attempted, run.failed = len(ends), bad
     fpt = flops.train_flops_per_token(program["model"], tr["seq_len"])
     run.data.update(

@@ -57,8 +57,17 @@ def token_altered(setattr_, vocab):
 
 
 def cache_unchanged(setattr_):
-    def wrap(orig, state, slots, tokens, *a):
-        keep = jax.tree.map(jnp.copy, state)
-        _, nxt = orig(state, slots, tokens, *a)
-        return keep, nxt
-    _serve_call(setattr_, wrap, "_apply")
+    """Planted in the compiled serve step itself, before the engine is
+    built: the step hands back the pools it was given, so it keeps no
+    copy beside them."""
+    from repro.launch import steps
+    orig = steps.make_paged_serve_fn
+
+    def make(*a, **kw):
+        fn = orig(*a, **kw)
+
+        def step(params, state, *rest):
+            nxt, _ = fn(params, state, *rest)
+            return nxt, state
+        return step
+    setattr_(steps, "make_paged_serve_fn", make)

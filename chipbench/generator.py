@@ -1,11 +1,14 @@
 """The one traffic generator: open-loop request schedules from a mix's
 parameters (``traffic/<mix>.json``) and the seed.
 
-Every seed gets the same set of sizes and arrival gaps, in another order:
-lengths are the distribution's quantiles at (i + 0.5) / n and the gaps
-the exponential (Poisson) quantiles, each permuted by the seed; the seed
-also draws the token ids and which requests sample. So two seeds differ
-in order and content, not in the amount of work.
+Every run gets the same set of sizes and arrival gaps: lengths are the
+distribution's quantiles at (i + 0.5) / n and the gaps the exponential
+(Poisson) quantiles, each shuffled, and a fixed share of the requests
+sample. The shuffle is drawn from the mix's ``schedule_seed`` where it
+names one: then every run has one schedule, the same work arriving in the
+same order, and the seed draws only the token ids and each request's
+sampling seed; a result on such a mix holds for that one order. Without
+it the run's seed draws the order too.
 """
 from __future__ import annotations
 
@@ -43,17 +46,18 @@ def quantile_lengths(spec: Dict, n: int) -> np.ndarray:
 
 def open_loop(mix: Dict, seconds: float, seed: int, vocab: int) -> List[Req]:
     """round(rate * seconds) requests, all due inside the window."""
-    rng = np.random.default_rng(seed)
+    order = np.random.default_rng(mix.get("schedule_seed", seed))
     rate = mix["rate_per_s"]
     n = max(1, int(round(rate * seconds)))
     u = (np.arange(n) + 0.5) / n
-    gaps = rng.permutation(-np.log1p(-u) / rate)
+    gaps = order.permutation(-np.log1p(-u) / rate)
     due = np.cumsum(gaps) * (seconds * n / (n + 1)) / gaps.sum()
-    prompt = rng.permutation(quantile_lengths(mix["prompt_len"], n))
-    out = rng.permutation(quantile_lengths(mix["output_len"], n))
+    prompt = order.permutation(quantile_lengths(mix["prompt_len"], n))
+    out = order.permutation(quantile_lengths(mix["output_len"], n))
     n_sampled = int(round(mix["sampled_share"] * n))
     sampled = np.zeros(n, bool)
-    sampled[rng.permutation(n)[:n_sampled]] = True
+    sampled[order.permutation(n)[:n_sampled]] = True
+    rng = np.random.default_rng(seed)
     smp = mix["sampling"]
     reqs = []
     for i in range(n):

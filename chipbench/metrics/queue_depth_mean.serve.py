@@ -2,7 +2,7 @@
 sampled by the benchmark's open-loop driver around every submission and
 ``Scheduler.step``."""
 META = {"layer": "scheduler", "unit": "requests", "source": "host_clock",
-        "moves": "ttft_p90_ms"}
+        "moves": "ttft_p50_ms"}
 
 
 def read(run):

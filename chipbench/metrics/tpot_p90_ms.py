@@ -1,5 +1,6 @@
-"""90th percentile over the requests due in the window of (done - first
-token) / (output tokens - 1)."""
+"""90th percentile over every inter-token gap of the requests due in the
+window (``drivers/serve.TokenGaps``): each of a few thousand gaps counts,
+so no one request's place in the order of arrivals moves it."""
 META = {"unit": "ms", "source": "host_clock"}
 
 

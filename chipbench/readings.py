@@ -18,6 +18,7 @@ from __future__ import annotations
 import argparse
 import json
 import sys
+import time
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent.parent
@@ -46,7 +47,7 @@ def main(argv=None) -> int:
     readings = {"program": {}, "control": {}}
     for seed in (int(s) for s in args.seeds.split(",")):
         res, r = run.execute(args.workload, seed, args.seconds, False,
-                             bool(args.control))
+                             bool(args.control), t_start=time.perf_counter())
         checks = {k: v["value"] for k, v in res["checks"].items()}
         side = "control" if args.control else "program"
         got = {side: checks}

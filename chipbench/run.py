@@ -121,15 +121,7 @@ def _config_entry(bench, cell):
 
 
 def load_bench():
-    """``BENCHMARK.json``, with the cells of ``pending.json`` after its own:
-    cells built but not yet proven on the chip, which the command runs by
-    name and the benchmark's check never asks for."""
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    pending = json.loads((BENCH / "pending.json").read_text())
-    for key in ("configs", "workloads", "end_to_end", "per_layer"):
-        names = {e["name"] for e in bench[key]}
-        bench[key] += [e for e in pending[key] if e["name"] not in names]
-    return bench
+    return json.loads((ROOT / "BENCHMARK.json").read_text())
 
 
 def find_cell(bench, name):
@@ -150,19 +142,25 @@ def metrics_for(bench, cell, trace: bool):
                 else m["moves"] in names)]
 
 
-def read_metrics(run, entries):
+def reader(m):
+    """The reader of the metric entry ``m``, whose ``META`` has to agree
+    with the entry."""
     from chipbench import common
+    mod = common.load_module(BENCH / "metrics" / f"{m['name']}.py",
+                             "chipbench_metric_" + m["name"].replace(
+                                 ".", "_"))
+    for key in ("layer", "unit", "source", "moves"):
+        if key in mod.META and mod.META[key] != m.get(key):
+            raise RuntimeError(f"metric {m['name']}: {key} "
+                               f"{mod.META[key]!r} in its reader, "
+                               f"{m.get(key)!r} in BENCHMARK.json")
+    return mod
+
+
+def read_metrics(run, entries):
     out = {}
     for m in entries:
-        mod = common.load_module(BENCH / "metrics" / f"{m['name']}.py",
-                                 "chipbench_metric_" + m["name"].replace(
-                                     ".", "_"))
-        for key in ("layer", "unit", "source", "moves"):
-            if key in mod.META and mod.META[key] != m.get(key):
-                raise RuntimeError(f"metric {m['name']}: {key} "
-                                   f"{mod.META[key]!r} in its reader, "
-                                   f"{m.get(key)!r} in BENCHMARK.json")
-        value = mod.read(run)
+        value = reader(m).read(run)
         if value is not None:
             out[m["name"]] = {"value": float(value), "unit": m["unit"]}
     return out

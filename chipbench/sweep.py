@@ -46,9 +46,10 @@ def main(argv=None) -> int:
                "failed": run.failed, "correct": res["correct"],
                "setup_s": run.setup_s,
                **{k: d.get(k) for k in (
-                   "ttft_p90_ms", "tpot_p90_ms", "serve_tokens_per_s",
-                   "queue_depth_mean", "queue_at_close", "unfinished_at_close",
-                   "drain_s", "late_p90_ms")}}
+                   "ttft_p50_ms", "ttft_p90_ms", "tpot_p90_ms",
+                   "serve_tokens_per_s", "queue_depth_mean", "queue_at_close",
+                   "unfinished_at_close", "drain_s", "late_p90_ms",
+                   "pages_in_use_mean", "pages_in_use_peak")}}
         print(json.dumps(row), flush=True)
     return 0
 

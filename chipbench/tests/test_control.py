@@ -12,7 +12,7 @@ import sizes
 
 @pytest.mark.parametrize("workload,small,seconds", [
     ("gpt2.train_lp", sizes.TRAIN, 5.0),
-    ("qwen3.serve_chat", sizes.SERVE, 3.0),
+    ("qwen3.serve_chat", sizes.SERVE_DEEP, 3.0),
 ])
 def test_control_fails_where_the_program_passes(workload, small, seconds):
     res, run = sizes.execute(workload, small, seconds=seconds, control=True)
